@@ -292,6 +292,10 @@ def _build_scala(spec: ExperimentSpec, *, mesh=None,
         params = {"client": _broadcast_slots(wc, param_slots), "server": ws}
     else:
         model, params = text_split_init(spec, param_slots)
+    # the initial params live on the host: init() places a fresh device
+    # copy per call, so the program never holds a second resident copy
+    # of the (K, ...) stacked state beside the one it trains
+    params = jax.device_get(params)
 
     if ex.mode == "async":
         delays = ex.make_delays()
@@ -317,14 +321,15 @@ def _build_scala(spec: ExperimentSpec, *, mesh=None,
         sched_mesh = mesh if ex.arrival == "topk:sharded" else None
 
         def init() -> ProgramState:
+            dev = jax.tree.map(jnp.asarray, params)
             afed = fed.init_async_state(
-                _fed_key(spec), params["client"], delays, aggregator=agg,
-                server_optimizer=server_opt, server_params=params["server"],
+                _fed_key(spec), dev["client"], delays, aggregator=agg,
+                server_optimizer=server_opt, server_params=dev["server"],
                 snapshots=ex.snapshots, ring_size=ex.ring_size,
                 num_clients=slots, mesh=sched_mesh, guards=guards)
             if pager is not None:
                 pager.reset()
-            return ProgramState(inner=engine.init_train_state(params, opt),
+            return ProgramState(inner=engine.init_train_state(dev, opt),
                                 fed=afed)
 
         if paged:
@@ -374,13 +379,14 @@ def _build_scala(spec: ExperimentSpec, *, mesh=None,
                       or (guards is not None and guards.stateful))
 
         def init() -> ProgramState:
+            dev = jax.tree.map(jnp.asarray, params)
             fed_state = (fed.init_fed_state(_fed_key(spec), agg, scheduler,
                                             num_clients=slots,
                                             server_optimizer=server_opt,
-                                            server_params=params["server"],
+                                            server_params=dev["server"],
                                             faults=faults, guards=guards)
                          if thread_fed else ())
-            return ProgramState(inner=engine.init_train_state(params, opt),
+            return ProgramState(inner=engine.init_train_state(dev, opt),
                                 fed=fed_state)
 
         if thread_fed:

@@ -26,6 +26,7 @@ With ``checkpoint_dir=""`` the model is freshly initialised from
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 from dataclasses import dataclass
@@ -217,10 +218,12 @@ def build_serve(spec: ServeSpec) -> ServeProgram:
         temperature=spec.temperature, seed=spec.seed,
         admission=spec.admission)
 
-    prefill = jax.jit(lambda tokens: T.forward_prefill_cached(
-        params, {"tokens": tokens}, cfg, spec.max_len))
-    predict = jax.jit(lambda batch: T.forward(
-        params, batch, cfg, remat=False)[0])
+    # params enter the jitted fns as arguments, not captured constants
+    prefill = functools.partial(jax.jit(
+        lambda p, tokens: T.forward_prefill_cached(
+            p, {"tokens": tokens}, cfg, spec.max_len)), params)
+    predict = functools.partial(jax.jit(
+        lambda p, batch: T.forward(p, batch, cfg, remat=False)[0]), params)
 
     return ServeProgram(spec=spec, cfg=cfg, params=params, engine=engine,
                         prefill=prefill, admit=engine.admit,

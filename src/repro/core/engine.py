@@ -90,7 +90,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.configs.base import ScalaConfig
 from repro.core import losses
 from repro.core.label_stats import client_and_concat_priors, histogram
@@ -610,6 +609,25 @@ def _dp_specs(mesh, axes: MeshAxes, tree):
             "server": jax.tree.map(lambda _: P(), tree["server"])}
 
 
+def pin_dp_layout(mesh, state: TrainState) -> TrainState:
+    """Pin the ``lace_dp`` layout on a round's output state: the client
+    half split over the client mesh axes, the server half replicated.
+    The masked round's FL-phase average runs outside the shard_map, and
+    without the pin GSPMD replicates the re-stacked client half on every
+    device."""
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from repro.sharding.logical import place
+
+    axes = mesh_axes(mesh)
+    specs = TrainState(params=_dp_specs(mesh, axes, state.params),
+                       opt_state=_dp_specs(mesh, axes, state.opt_state),
+                       step=P())
+    return place(state, jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                                     is_leaf=lambda s: isinstance(s, P)))
+
+
 def client_shard_count(mesh) -> int:
     """How many ways the stacked client axis splits on this mesh — the
     product of the client mesh-axis sizes (:func:`mesh_axes`). 1 on a
@@ -653,9 +671,9 @@ def local_step(model: SplitModel, params, batch, scala: ScalaConfig, *,
                                               precision=precision)
             return sgd_apply(p, grads, lr), metrics
 
-        fn = compat.shard_map(body, mesh=mesh,
-                              in_specs=(p_specs, batch_specs),
-                              out_specs=(p_specs, m_specs), check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh,
+                           in_specs=(p_specs, batch_specs),
+                           out_specs=(p_specs, m_specs), check_vma=False)
         return fn(params, batch)
 
     grads, metrics = split_step_grads(model, params, batch, scala,
@@ -718,9 +736,9 @@ def make_split_step(model: SplitModel, scala: ScalaConfig, *,
             args = (state, batch) if mask is None else (state, batch, mask)
             in_specs = ((s_specs, batch_specs) if mask is None
                         else (s_specs, batch_specs, P(axes.client or None)))
-            fn = compat.shard_map(body, mesh=mesh, in_specs=in_specs,
-                                  out_specs=(s_specs, m_specs),
-                                  check_vma=False)
+            fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                               out_specs=(s_specs, m_specs),
+                               check_vma=False)
             return fn(*args)
 
         return step
@@ -1099,7 +1117,7 @@ def make_round_runner(model: SplitModel, scala: ScalaConfig, *,
                                     step=st.step)
                 return st, metrics
 
-            fn = compat.shard_map(
+            fn = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(s_specs, rb_specs, cspec, cspec),
                 out_specs=(s_specs, m_specs), check_vma=False)
@@ -1295,6 +1313,8 @@ def make_round_runner(model: SplitModel, scala: ScalaConfig, *,
                                        "server": new_ws},
                                opt_state=state.opt_state, step=state.step)
 
+        if backend == "lace_dp":
+            state = pin_dp_layout(mesh, state)
         if fed_state is None:
             return state, metrics
         out_fed = {"sched": sched_state, "agg": agg_state}

@@ -393,8 +393,6 @@ def sharded_arrival_cohort(finish_time, cohort: int, version, *, mesh):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
-
     axes = engine.mesh_axes(mesh)
     n_shards = engine.client_shard_count(mesh)
     K = finish_time.shape[0]
@@ -425,8 +423,8 @@ def sharded_arrival_cohort(finish_time, cohort: int, version, *, mesh):
         mask_l = jnp.zeros((K_l,), jnp.float32).at[loc].set(1.0, mode="drop")
         return idx, mask_l, t_event
 
-    fn = compat.shard_map(body, mesh=mesh, in_specs=(cspec, cspec),
-                          out_specs=(P(), cspec, P()), check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(cspec, cspec),
+                       out_specs=(P(), cspec, P()), check_vma=False)
     return fn(finish_time, version)
 
 
@@ -1132,7 +1130,6 @@ def _make_async_runner_dp(model, scala, *, boundary, delays, cohort, opt,
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro import compat
     from repro.sharding.logical import round_specs
 
     if mesh is None or batch_specs is None:
@@ -1325,7 +1322,7 @@ def _make_async_runner_dp(model, scala, *, boundary, delays, cohort, opt,
                            t_event=t_event, server_version=new_version)
             return new_st, new_af, metrics
 
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body, mesh=mesh,
             in_specs=(s_specs, a_specs, rb_specs, cspec),
             out_specs=(s_specs, a_specs, m_specs), check_vma=False)

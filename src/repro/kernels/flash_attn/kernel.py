@@ -74,7 +74,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def flash_attention_pallas(q, k, v, *, causal: bool = True, window=None,
                            qb: int = 128, kb: int = 128, scale=None,
-                           interpret: bool = True):
+                           interpret: bool | None = None):
     """q,k,v: (BH, S, hd) — batch*heads flattened (kv already repeated).
     Returns (BH, S, hd)."""
     BH, S, hd = q.shape

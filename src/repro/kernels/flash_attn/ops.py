@@ -11,7 +11,7 @@ from repro.kernels.flash_attn.kernel import flash_attention_pallas
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q: (B,S,H,hd); k/v: (B,S,KV,hd) — KV heads repeated as needed."""
     B, S, H, hd = q.shape
     KV = k.shape[2]

@@ -1,15 +1,17 @@
-"""Env-forced Pallas interpret mode for CI kernel legs.
+"""Where the package's Pallas kernels run in interpret mode.
 
-``REPRO_PALLAS_INTERPRET=1`` forces every ``pl.pallas_call`` in this
-package to run in interpret mode regardless of the ``interpret=``
-argument the caller passed. CPU CI uses it to exercise the *kernel*
-path of the oracle tests (kernels/{lace,flash_attn,mlstm}) instead of
-only the jnp ref — the same tests then validate the Mosaic lowering
-when run on a TPU host with the variable unset.
+A kernel's ``interpret=None`` default resolves to interpret mode only on
+the CPU backend, where Mosaic cannot run; on a TPU the same
+``pallas_call`` lowers to Mosaic. ``REPRO_PALLAS_INTERPRET=1`` forces
+interpret mode on regardless of the ``interpret=`` argument, so CPU CI
+exercises the kernel path of the oracle tests instead of only the jnp
+reference.
 """
 from __future__ import annotations
 
 import os
+
+import jax
 
 
 def force_interpret() -> bool:
@@ -17,6 +19,10 @@ def force_interpret() -> bool:
     return os.environ.get("REPRO_PALLAS_INTERPRET", "") not in ("", "0")
 
 
-def resolve(interpret: bool) -> bool:
+def resolve(interpret: bool | None) -> bool:
     """The effective interpret flag for a ``pallas_call`` site."""
-    return True if force_interpret() else bool(interpret)
+    if force_interpret():
+        return True
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return bool(interpret)

@@ -257,10 +257,10 @@ def lace_loss_dp(feats, w_head, labels, prior_rows, prior_ids, weights,
     ``token_axes``; w_head replicated. Falls back to ``lace_loss`` when
     there is no ambient mesh (CPU tests / host training).
     """
-    from repro import compat
+    from repro.sharding.logical import ambient_mesh, in_shard_map
 
-    mesh = compat.ambient_mesh()
-    if mesh is None or not mesh.axis_names or compat.in_shard_map():
+    mesh = ambient_mesh()
+    if mesh is None or in_shard_map():
         return lace_loss(feats, w_head, labels, prior_rows, prior_ids,
                          weights, tau, eps, chunk)
     present = lambda axes: tuple(a for a in axes if a in mesh.axis_names)
@@ -290,7 +290,7 @@ def lace_loss_dp(feats, w_head, labels, prior_rows, prior_ids, weights,
     in_specs = (gtd, P(None, None), gt,
                 pr_spec if prior_rows is not None else P(),
                 gt if weights is not None else P())
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda f, w, l, pr, wt: local(
             f, w, l, pr if prior_rows is not None else None,
             wt if weights is not None else None),
@@ -392,6 +392,17 @@ def _side_nll_stats(z, l_c):
     return nll, p, onehot
 
 
+def _fwd2_chunk_sums(z, l_c, w_c, lp_s, lp_k, tau):
+    """Both sides' weighted NLL sums for one chunk's shared logits ``z``.
+
+    The one value path of the fused ops: :func:`lace2_nll_sum` /
+    :func:`lace2_loss` and :func:`lace2_grads` all reduce through it, so
+    their loss values are the same computation, not two that agree."""
+    nll_s = _nll_from_logits(_side_adjust(z, lp_s, tau), l_c)
+    nll_k = _nll_from_logits(_side_adjust(z, lp_k, tau), l_c)
+    return jnp.sum(nll_s * w_c), jnp.sum(nll_k * w_c)
+
+
 def _fwd2_impl(feats, w_head, labels, prior_rows_s, prior_ids_s,
                prior_rows_k, prior_ids_k, weights, tau, eps, chunk, mean):
     _check_args2(feats, w_head, labels, prior_rows_s, prior_ids_s,
@@ -410,10 +421,8 @@ def _fwd2_impl(feats, w_head, labels, prior_rows_s, prior_ids_s,
         nll_s_sum, nll_k_sum, w_sum = carry
         f_c, l_c, w_c = inp
         z = _chunk_logits(f_c, w_head, None, tau)        # ONE matmul
-        nll_s = _nll_from_logits(_side_adjust(z, lp_s, tau), l_c)
-        nll_k = _nll_from_logits(_side_adjust(z, lp_k, tau), l_c)
-        return (nll_s_sum + jnp.sum(nll_s * w_c),
-                nll_k_sum + jnp.sum(nll_k * w_c),
+        sum_s, sum_k = _fwd2_chunk_sums(z, l_c, w_c, lp_s, lp_k, tau)
+        return (nll_s_sum + sum_s, nll_k_sum + sum_k,
                 w_sum + jnp.sum(w_c)), None
 
     zero = jnp.zeros((), jnp.float32)
@@ -560,16 +569,19 @@ def lace2_grads(feats, w_head, labels, prior_rows_s, prior_ids_s,
         nll_s_sum, nll_k_sum, dw = carry
         f_c, l_c, w_c = inp
         z = _chunk_logits(f_c, w_head, None, tau)        # ONE matmul
-        nll_s, p_s, onehot = _side_nll_stats(_side_adjust(z, lp_s, tau), l_c)
-        nll_k, p_k, _ = _side_nll_stats(_side_adjust(z, lp_k, tau), l_c)
+        sum_s, sum_k = _fwd2_chunk_sums(z, l_c, w_c, lp_s, lp_k, tau)
+        # the barrier keeps XLA from fusing the value path into the
+        # softmax below, which would reorder its reductions
+        zg = jax.lax.optimization_barrier(z)
+        _, p_s, onehot = _side_nll_stats(_side_adjust(zg, lp_s, tau), l_c)
+        _, p_k, _ = _side_nll_stats(_side_adjust(zg, lp_k, tau), l_c)
         gi_s = (p_s - onehot) * (w_c * scale)[..., None]
         gi_k = (p_k - onehot) * (w_c * scale)[..., None]
         w32 = w_head.astype(jnp.float32)
         df_s_c = jnp.einsum("gcv,dv->gcd", gi_s, w32)
         df_k_c = jnp.einsum("gcv,dv->gcd", gi_k, w32)
         dw = dw + jnp.einsum("gcd,gcv->dv", f_c.astype(jnp.float32), gi_s)
-        return (nll_s_sum + jnp.sum(nll_s * w_c),
-                nll_k_sum + jnp.sum(nll_k * w_c), dw), (df_s_c, df_k_c)
+        return (nll_s_sum + sum_s, nll_k_sum + sum_k, dw), (df_s_c, df_k_c)
 
     zero = jnp.zeros((), jnp.float32)
     (nll_s_sum, nll_k_sum, dw), (dfc_s, dfc_k) = jax.lax.scan(
@@ -600,10 +612,10 @@ def lace2_grads_dp(feats, w_head, labels, prior_rows_s, prior_ids_s,
     grads are the mathematically exact global-mean grads via explicit
     psums.
     """
-    from repro import compat
+    from repro.sharding.logical import ambient_mesh, in_shard_map
 
-    mesh = compat.ambient_mesh()
-    if mesh is None or not mesh.axis_names or compat.in_shard_map():
+    mesh = ambient_mesh()
+    if mesh is None or in_shard_map():
         out = lace2_grads(feats, w_head, labels, prior_rows_s, prior_ids_s,
                           prior_rows_k, prior_ids_k, weights, tau, eps, chunk)
         return out[:5]
@@ -649,7 +661,7 @@ def lace2_grads_dp(feats, w_head, labels, prior_rows_s, prior_ids_s,
                 ps_spec if has_s else P(),
                 pk_spec if has_k else P(),
                 gt if weights is not None else P())
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         lambda f, w, l, prs, prk, wt: local(
             f, w, l, prs if has_s else None, prk if has_k else None,
             wt if weights is not None else None),

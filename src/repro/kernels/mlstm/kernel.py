@@ -35,47 +35,57 @@ def _mlstm_kernel(q_ref, k_ref, v_ref, i_ref, f_ref, h_ref,
     q = q_ref[...].astype(jnp.float32)          # (L, dk)
     k = k_ref[...].astype(jnp.float32)
     v = v_ref[...].astype(jnp.float32)          # (L, dv)
-    ii = i_ref[...].astype(jnp.float32)         # (L,)
-    ff = f_ref[...].astype(jnp.float32)         # (L,) log-sigmoid forget
+    ii = i_ref[...].astype(jnp.float32)         # (L, 1)
+    ff = f_ref[...].astype(jnp.float32)         # (L, 1) log-sigmoid forget
 
     C0 = C_scr[...]
-    n0 = n_scr[...]
-    m0 = m_scr[0]
+    n0 = n_scr[...]                             # (1, dk)
+    m0 = m_scr[...]                             # (1, 1)
 
-    b = jnp.cumsum(ff)                          # decay from chunk start
-    a = ii - b
-    a_max = jax.lax.cummax(a, axis=0)
-    m_t = jnp.maximum(m0 + b, b + a_max)        # (L,)
+    # Gate prefix scans as masked (L, L) reductions: row t sees s <= t.
+    row = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
+    causal = col <= row
 
-    w0 = jnp.exp(m0 + b - m_t)                  # (L,)
-    h_inter = (q @ C0) * w0[:, None]            # (L, dv)
-    d_inter = (q @ n0[:, None])[:, 0] * w0      # (L,)
+    def as_row(x):                              # (L, 1) -> (1, L)
+        return jnp.sum(jnp.where(row == col, x, 0.0), axis=0, keepdims=True)
+
+    ff_r, ii_r = as_row(ff), as_row(ii)
+    b = jnp.sum(jnp.where(causal, ff_r, 0.0), axis=1, keepdims=True)  # (L, 1)
+    b_r = as_row(b)
+    a_max = jnp.max(jnp.where(causal, ii_r - b_r, -jnp.inf), axis=1,
+                    keepdims=True)              # cummax of i - b
+    m_t = jnp.maximum(m0 + b, b + a_max)        # (L, 1)
+
+    w0 = jnp.exp(m0 + b - m_t)                  # (L, 1)
+    h_inter = (q @ C0) * w0                     # (L, dv)
+    d_inter = jnp.sum(q * n0, axis=1, keepdims=True) * w0   # (L, 1)
 
     # intra-chunk decay matrix D[t,s] = exp(b_t - b_s + i_s - m_t), s<=t
-    Dlog = b[:, None] - b[None, :] + ii[None, :] - m_t[:, None]
-    row = jax.lax.broadcasted_iota(jnp.int32, Dlog.shape, 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, Dlog.shape, 1)
-    D = jnp.where(col <= row, jnp.exp(Dlog), 0.0)
+    D = jnp.where(causal, jnp.exp(b - b_r + ii_r - m_t), 0.0)
 
     scores = (q @ k.T) * D                      # (L, L)
     h_intra = scores @ v
-    d_intra = jnp.sum(scores, axis=1)
+    d_intra = jnp.sum(scores, axis=1, keepdims=True)
     denom = jnp.maximum(jnp.abs(d_inter + d_intra), jnp.exp(-m_t))
-    h_ref[...] = ((h_inter + h_intra) / denom[:, None]).astype(h_ref.dtype)
+    h_ref[...] = ((h_inter + h_intra) / denom).astype(h_ref.dtype)
 
     # state to end of chunk
-    F = b[L - 1]
-    m_new = jnp.maximum(m0 + F, F + a_max[L - 1])
+    F = b[L - 1:L]                              # (1, 1)
+    m_new = jnp.maximum(m0 + F, F + a_max[L - 1:L])
     wC0 = jnp.exp(m0 + F - m_new)
-    wks = jnp.exp(F - b + ii - m_new)           # (L,)
-    C_scr[...] = C0 * wC0 + (k * wks[:, None]).T @ v
-    n_scr[...] = n0 * wC0 + jnp.sum(k * wks[:, None], axis=0)
-    m_scr[0] = m_new
+    kw = k * jnp.exp(F - b + ii - m_new)        # (L, dk)
+    C_scr[...] = C0 * wC0 + kw.T @ v
+    n_scr[...] = n0 * wC0 + jnp.sum(kw, axis=0, keepdims=True)
+    m_scr[...] = m_new
 
 
 def mlstm_chunk_pallas(q, k, v, i_raw, f_log, *, chunk: int = 64,
-                       interpret: bool = True):
-    """q,k: (S, dk); v: (S, dv); gates (S,). Single head. Returns (S, dv)."""
+                       interpret: bool | None = None):
+    """q,k: (S, dk); v: (S, dv); gates (S,). Single head. Returns (S, dv).
+
+    Gates travel as (S, 1) columns in (L, 1) blocks: Mosaic refuses a
+    rank-1 block that is not a multiple of its 128 tile."""
     S, dk = q.shape
     dv = v.shape[1]
     L = min(chunk, S)
@@ -90,15 +100,15 @@ def mlstm_chunk_pallas(q, k, v, i_raw, f_log, *, chunk: int = 64,
             pl.BlockSpec((L, dk), lambda c: (c, 0)),
             pl.BlockSpec((L, dk), lambda c: (c, 0)),
             pl.BlockSpec((L, dv), lambda c: (c, 0)),
-            pl.BlockSpec((L,), lambda c: (c,)),
-            pl.BlockSpec((L,), lambda c: (c,)),
+            pl.BlockSpec((L, 1), lambda c: (c, 0)),
+            pl.BlockSpec((L, 1), lambda c: (c, 0)),
         ],
         out_specs=pl.BlockSpec((L, dv), lambda c: (c, 0)),
         out_shape=jax.ShapeDtypeStruct((S, dv), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((dk, dv), jnp.float32),
-            pltpu.VMEM((dk,), jnp.float32),
-            pltpu.VMEM((1,), jnp.float32),
+            pltpu.VMEM((1, dk), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
         ],
         interpret=interp.resolve(interpret),
-    )(q, k, v, i_raw, f_log)
+    )(q, k, v, i_raw[:, None], f_log[:, None])

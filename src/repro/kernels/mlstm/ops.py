@@ -10,7 +10,7 @@ from repro.kernels.mlstm.kernel import mlstm_chunk_pallas
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
 def mlstm_chunkwise(q, k, v, i_raw, f_log, *, chunk: int = 64,
-                    interpret: bool = True):
+                    interpret: bool | None = None):
     """q,k,v: (B,S,H,hd); i_raw/f_log: (B,S,H). Returns h (B,S,H,hd)."""
     def per_head(q1, k1, v1, i1, f1):
         return mlstm_chunk_pallas(q1, k1, v1, i1, f1, chunk=chunk,

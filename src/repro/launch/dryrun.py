@@ -18,7 +18,6 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec  # noqa: E402
 
-from repro import compat
 from repro.configs import ASSIGNED_ARCHS, INPUT_SHAPES, ScalaConfig, get_config, get_shape  # noqa: E402
 from repro.core.scala import (scala_local_step_fused,  # noqa: E402
                               scala_local_step_fused_dp,
@@ -141,7 +140,7 @@ def dryrun_one(arch: str, shape_name: str, *, multi_pod: bool = False,
         record.update(meta)
 
         t0 = time.time()
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             jitted = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh)
             lowered = jitted.lower(*args)
             t_lower = time.time() - t0

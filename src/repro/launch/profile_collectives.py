@@ -13,7 +13,6 @@ import argparse  # noqa: E402
 
 import jax  # noqa: E402
 
-from repro import compat
 from repro.launch.dryrun import build_step  # noqa: E402
 from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.perf.roofline import collective_breakdown  # noqa: E402
@@ -36,7 +35,7 @@ def main():
     mesh = make_production_mesh(multi_pod=args.multi_pod)
     step, fargs, in_sh, out_sh, meta, cfg = build_step(
         args.arch, args.shape, mesh)
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         hlo = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh) \
             .lower(*fargs).compile().as_text()
     if args.save:

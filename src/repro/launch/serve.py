@@ -77,6 +77,7 @@ def _percentile(xs, q):
 
 def main():
     from repro.api import ServeSpec, build_serve
+    from repro.launch.compile_cache import use_compile_cache
     from repro.serve import Request
 
     ap = argparse.ArgumentParser()
@@ -101,6 +102,7 @@ def main():
     ap.add_argument("--reference", action="store_true",
                     help="token-by-token baseline instead of the engine")
     args = ap.parse_args()
+    use_compile_cache()
 
     max_len = args.max_len or (args.prompt_len + args.gen)
     key = jax.random.PRNGKey(args.seed)

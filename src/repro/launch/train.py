@@ -81,6 +81,7 @@ from repro import api
 from repro.checkpoint import save
 from repro.configs import ScalaConfig
 from repro.core import engine
+from repro.launch.compile_cache import use_compile_cache
 
 
 def spec_from_args(args) -> api.ExperimentSpec:
@@ -346,6 +347,7 @@ def _run_no_scan(spec: api.ExperimentSpec, args):
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    use_compile_cache()
 
     try:
         if args.config:

@@ -136,7 +136,7 @@ class ServeEngine:
             jax.eval_shape(lambda: T.init_decode_cache(
                 cfg, slots, max_len, dtype_of(cfg.dtype))))
 
-        def one(tok, idx, cache1):
+        def one(params, tok, idx, cache1):
             cb = jax.tree_util.tree_map_with_path(
                 lambda p, a: a[:, None] if is_group_path(p) else a[None],
                 cache1)
@@ -146,17 +146,20 @@ class ServeEngine:
                 lambda p, a: a[:, 0] if is_group_path(p) else a[0], nc)
             return logits[0, 0], nc
 
-        slot_decode = jax.vmap(one, in_axes=(0, 0, axes),
+        slot_decode = jax.vmap(one, in_axes=(None, 0, 0, axes),
                                out_axes=(0, axes))
 
-        def step_fn(cache, table, toks, idxs, ctr):
+        # params are an argument of every jitted dispatch, never a
+        # closed-over constant: at full width a captured copy would be
+        # embedded in each executable (GBs of constants per compile)
+        def step_fn(params, cache, table, toks, idxs, ctr):
             dense = self.ops.gather(cache, table)
-            logits, new_dense = slot_decode(toks, idxs, dense)
+            logits, new_dense = slot_decode(params, toks, idxs, dense)
             logits = logits.astype(jnp.float32)
             nxt = self._pick(logits, ctr)
             return self.ops.scatter(cache, new_dense, table, idxs), nxt, logits
 
-        self._step = jax.jit(step_fn, donate_argnums=(0,))
+        self._step = jax.jit(step_fn, donate_argnums=(1,))
         self._admits: Dict[int, object] = {}  # prompt_len -> jitted admit
 
         # host-side bookkeeping
@@ -185,14 +188,14 @@ class ServeEngine:
     def _admit_fn(self, prompt_len: int):
         fn = self._admits.get(prompt_len)
         if fn is None:
-            def admit_fn(cache, prompt, table_row, slot, ctr):
+            def admit_fn(params, cache, prompt, table_row, slot, ctr):
                 logits, req = T.forward_prefill_cached(
-                    self.params, {"tokens": prompt}, self.cfg, self.max_len)
+                    params, {"tokens": prompt}, self.cfg, self.max_len)
                 cache = self.ops.admit(cache, req, table_row, slot)
                 lg = logits[0, 0].astype(jnp.float32)
                 return cache, self._pick(lg[None], ctr)[0], lg
             fn = self._admits[prompt_len] = jax.jit(
-                admit_fn, donate_argnums=(0,))
+                admit_fn, donate_argnums=(1,))
         return fn
 
     # -- scheduling --------------------------------------------------------
@@ -218,7 +221,7 @@ class ServeEngine:
 
         prompt = jnp.asarray(req.tokens[None].astype(np.int32))
         self._cache, tok0, lg = self._admit_fn(len(req.tokens))(
-            self._cache, prompt, jnp.asarray(row), jnp.int32(slot),
+            self.params, self._cache, prompt, jnp.asarray(row), jnp.int32(slot),
             jnp.int32(self._ctr))
         self._ctr += 1
         tok0 = int(tok0)
@@ -272,7 +275,7 @@ class ServeEngine:
         toks = np.array([s.last_tok for s in self._slot], np.int32)
         idxs = np.array([s.length for s in self._slot], np.int32)
         self._cache, nxt, logits = self._step(
-            self._cache, jnp.asarray(self._table), jnp.asarray(toks),
+            self.params, self._cache, jnp.asarray(self._table), jnp.asarray(toks),
             jnp.asarray(idxs), jnp.int32(self._ctr))
         self._ctr += 1
         nxt = np.asarray(nxt)

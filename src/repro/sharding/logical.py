@@ -176,6 +176,34 @@ def client_scalar_spec(mesh: Mesh, n: int) -> PartitionSpec:
 CONSTRAIN = True
 
 
+def ambient_mesh():
+    """The abstract mesh made ambient by ``jax.set_mesh`` (or a
+    ``jax.shard_map`` body), or ``None`` when there is none."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
+
+
+def in_shard_map() -> bool:
+    """True while tracing a ``jax.shard_map`` body: the ambient mesh then
+    has Manual axes, data is already device-local, and sharding
+    constraints / ambient-mesh collectives must be skipped."""
+    mesh = ambient_mesh()
+    return mesh is not None and bool(mesh.manual_axes)
+
+
+def place(tree, shardings):
+    """Lay ``tree`` out as ``shardings`` (a matching tree of
+    ``NamedSharding``) inside jit: a sharding constraint on ``Auto``
+    axes, ``jax.sharding.reshard`` on a mesh whose axes are all
+    ``Explicit``, where a constraint would only assert."""
+    def one(x, s):
+        if s.mesh.are_all_axes_explicit:
+            return jax.sharding.reshard(x, s)
+        return jax.lax.with_sharding_constraint(x, s)
+
+    return jax.tree.map(one, tree, shardings)
+
+
 def constrain(x, *axes):
     """``with_sharding_constraint`` against the *ambient* abstract mesh.
 
@@ -183,22 +211,22 @@ def constrain(x, *axes):
     names. Axes missing from the current mesh are dropped; a dim whose
     size does not divide the requested axes is left unconstrained. Under
     no mesh (CPU unit tests, host training) this is a no-op, so model
-    code can call it unconditionally.
+    code can call it unconditionally. It also holds under a mesh whose
+    axes are all ``Explicit`` (the ``jax.make_mesh`` default): see
+    :func:`place`.
 
     XLA's sharding propagation over the SCALA step has a failure mode
     where the server-trunk batch dim de-shards (involuntary full
     rematerialization -> every device computes the full concatenated
     batch). Pinning the residual stream's batch dim to ("pod","data")
-    removes ~16x redundant compute+collectives; see EXPERIMENTS.md §Perf.
+    removes the redundant compute and collectives.
     """
     if not CONSTRAIN:
         return x
-    from repro import compat
-
-    mesh = compat.ambient_mesh()
-    if mesh is None or not mesh.axis_names:
+    mesh = ambient_mesh()
+    if mesh is None:
         return x
-    manual = compat.manual_axis_names(mesh)  # inside shard_map: already local
+    manual = set(mesh.manual_axes)  # inside shard_map: already local
     sizes = {k: v for k, v in dict(mesh.shape).items() if k not in manual}
     spec = []
     for a, dim in zip(axes, x.shape):
@@ -214,4 +242,4 @@ def constrain(x, *axes):
             spec.append(names if len(names) > 1 else names[0])
     if all(s is None for s in spec):
         return x
-    return jax.lax.with_sharding_constraint(x, PartitionSpec(*spec))
+    return place(x, NamedSharding(mesh, PartitionSpec(*spec)))

@@ -536,6 +536,7 @@ sys.path.insert(0, "src")
 import json
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import fed, optim
 from repro.configs import ScalaConfig, get_config
@@ -597,9 +598,13 @@ st_m, fs2, m_m = r_m(st0, rb, sizes, fs)
 err["masked_finite"] = int(all(
     bool(jnp.isfinite(l).all()) for l in jax.tree.leaves(st_m.params))
     and bool(jnp.isfinite(jnp.asarray(m_m["loss_server"]))))
-err["masked_slots_unified"] = int(bool(jnp.allclose(
-    jax.tree.leaves(st_m.params["client"])[0][0],
-    jax.tree.leaves(st_m.params["client"])[0][1])))
+# the client half comes back split over the data axis: read it on host
+leaf = jax.tree.leaves(st_m.params["client"])[0]
+err["masked_client_sharded"] = int(
+    leaf.sharding.spec[0] == "data"
+    and {s.data.shape[0] for s in leaf.addressable_shards} == {1})
+leaf_m = np.asarray(leaf)
+err["masked_slots_unified"] = int(bool(np.allclose(leaf_m[0], leaf_m[1])))
 print("RESULT " + json.dumps(err))
 """
 
@@ -628,6 +633,7 @@ def test_dp_backend_round_scan_matches_lace_and_runs_masked():
     assert err["loss"] < 1e-5, err
     assert err["masked_finite"] == 1, err
     assert err["masked_slots_unified"] == 1, err
+    assert err["masked_client_sharded"] == 1, err
 
 
 # --------------------------------------------------------------------------
@@ -759,6 +765,7 @@ sys.path.insert(0, "src")
 import json
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro import fed, optim
 from repro.configs import ScalaConfig, get_config
@@ -858,11 +865,11 @@ for snapshots, slots in (("dense", C), ("delta", 1)):
     for _ in range(3):
         st, af, mm = rr(st, af, rb, sizes)
     if snapshots == "dense":
-        ref_c = jax.tree.leaves(st.params["client"])[0][0]
+        ref_c = np.asarray(jax.tree.leaves(st.params["client"])[0])[0]
         ref_v = jnp.asarray(af.version)
     else:
-        res["delta_params"] = float(jnp.max(jnp.abs(
-            jax.tree.leaves(st.params["client"])[0][0] - ref_c)))
+        res["delta_params"] = float(np.max(np.abs(
+            np.asarray(jax.tree.leaves(st.params["client"])[0])[0] - ref_c)))
         res["delta_versions_ok"] = int(
             (jnp.asarray(af.version) == ref_v).all())
 print("RESULT " + json.dumps(res))

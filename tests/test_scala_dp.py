@@ -60,8 +60,7 @@ from repro.configs.base import InputShape
 shape = InputShape(name="t", seq_len=S, global_batch=C * BK, mode="train")
 b_sh, b_ax = ispec.train_batch_specs(cfg, shape, C)
 b_specs = tree_specs(b_ax, b_sh, mesh, RULES_DP)
-from repro import compat
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     dp_params, dp_m = jax.jit(
         lambda p, b: scala_local_step_fused_dp(model, p, b, sc, mesh,
                                                b_specs))(params, batch)

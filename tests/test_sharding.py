@@ -3,11 +3,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import abstract_mesh
+from jax.sharding import AbstractMesh
 from repro.sharding.logical import spec_for, tree_specs
 
-MESH = abstract_mesh((16, 16), ("data", "model"))
-MESH3 = abstract_mesh((2, 16, 16), ("pod", "data", "model"))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_basic_param_specs():

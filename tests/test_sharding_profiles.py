@@ -2,7 +2,6 @@
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 import pytest
 from jax.sharding import PartitionSpec as P
 
@@ -70,7 +69,7 @@ def test_constrain_is_noop_without_mesh():
 
 def test_constrain_applies_under_set_mesh():
     mesh = jax.make_mesh((1,), ("data",))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         def f(t):
             return constrain(t, ("pod", "data"), None)
         out = jax.jit(f)(jnp.ones((8, 4)))
@@ -79,7 +78,7 @@ def test_constrain_applies_under_set_mesh():
 
 def test_constrain_drops_indivisible_dims():
     mesh = jax.make_mesh((1,), ("data",))
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         # dim 7 % data-size... size 1 divides everything; use name miss
         out = jax.jit(lambda t: constrain(t, "absent_axis", None))(
             jnp.ones((7, 3)))
