@@ -37,6 +37,7 @@ import numpy as np
 
 from repro.api.build import RoundProgram, build
 from repro.api.specs import ExperimentSpec
+from repro.perf import trace
 
 
 # ---------------------------------------------------------------------------
@@ -105,6 +106,8 @@ class Trainer:
         self.state = self.program.init()
         self.history: List[Dict[str, float]] = []
         self.round = 0
+        # bytes of host batches handed to the device, sizes included
+        self.upload_bytes = 0
         self._rpc = self.program.metadata.get("rounds_per_call", 1)
         self._cfg = spec.model_config()
         if spec.data.kind == "image_synthetic":
@@ -138,6 +141,7 @@ class Trainer:
         else:
             rb = lm_round_batches(self._data, selected, sc.server_batch,
                                   sc.local_iters, self._rng)
+        self.upload_bytes += sum(v.nbytes for v in rb.values())
         sizes = jnp.asarray(rb.pop("sizes"))
         return {k: jnp.asarray(v) for k, v in rb.items()}, sizes
 
@@ -157,23 +161,27 @@ class Trainer:
         Returns the last executed round's scalar metrics as floats."""
         n = self._rpc if rounds is None else min(rounds, self._rpc)
         if self._rpc == 1:
-            batches, sizes = self._next_round_batches()
+            with trace.span("trainer.batches"):
+                batches, sizes = self._next_round_batches()
             self.state, metrics = self.program.step(self.state, batches,
                                                     sizes)
-            scalars = {k: float(v) for k, v in metrics.items()
-                       if jnp.ndim(v) == 0}
+            with trace.span("trainer.sync"):
+                scalars = {k: float(v) for k, v in metrics.items()
+                           if jnp.ndim(v) == 0}
             self.history.append(scalars)
             self.round += 1
             return scalars
-        per_round = [self._next_round_batches() for _ in range(n)]
-        batches = {k: jnp.stack([b[k] for b, _ in per_round])
-                   for k in per_round[0][0]}
-        sizes = jnp.stack([s for _, s in per_round])
+        with trace.span("trainer.batches"):
+            per_round = [self._next_round_batches() for _ in range(n)]
+            batches = {k: jnp.stack([b[k] for b, _ in per_round])
+                       for k in per_round[0][0]}
+            sizes = jnp.stack([s for _, s in per_round])
         self.state, metrics = self.program.step(self.state, batches, sizes)
         # per-round scalars carry the leading (n,) round axis now; ONE
         # device-to-host pull per metric for the whole chunk
-        stacked = {k: np.asarray(v) for k, v in metrics.items()
-                   if jnp.ndim(v) == 1}
+        with trace.span("trainer.sync"):
+            stacked = {k: np.asarray(v) for k, v in metrics.items()
+                       if jnp.ndim(v) == 1}
         scalars = None
         for r in range(n):
             scalars = {k: float(v[r]) for k, v in stacked.items()}
@@ -196,9 +204,9 @@ class Trainer:
         done = 0
         while done < n:
             k = min(self._rpc, n - done)
-            t0 = time.time()
+            t0 = time.perf_counter()
             self.step(k)
-            dt = time.time() - t0
+            dt = time.perf_counter() - t0
             done += k
             if on_round is not None:
                 for j in range(k):

@@ -18,6 +18,12 @@ parameterizes the two points where the variants actually differ:
                                uses plain SGD, eq. 7/9; the engine threads
                                any optimizer state through the params tree)
 
+In a profile the stages carry the scopes of :mod:`repro.perf.trace`:
+``scala.boundary`` (stage 1 and the losses of stages 3-4),
+``scala.client`` (stage 2 and each client's pullback), ``scala.trunk``
+(the server forward and pullbacks), ``scala.update`` (stage 5), and
+``scala.fed`` around a whole round (:func:`make_round_runner`).
+
 The variation points:
 
 * **loss backend** (stage 3-4 flavor):
@@ -95,6 +101,7 @@ from repro.core import losses
 from repro.core.label_stats import client_and_concat_priors, histogram
 from repro.core.split import redistribute, stack_client_params, weighted_mean
 from repro.optim import optimizers, schedules
+from repro.perf import trace
 
 BACKENDS = ("logits", "lace", "lace_dp")
 
@@ -263,6 +270,7 @@ def _priors(labels, weights, N, scala: ScalaConfig, axes: Optional[MeshAxes]):
     return p_k, p_s
 
 
+@trace.scoped("trunk")
 def _server_vjp(fwd, ws, acts):
     """Stage 3: linearize the server fn (server_fwd or server_trunk) wrt
     (w_s, x[, memory]) with positions closed over. Returns
@@ -288,6 +296,7 @@ def _server_vjp(fwd, ws, acts):
     return out, vjp, has_mem
 
 
+@trace.scoped("trunk")
 def _dual_pullbacks(vjp, g_s, g_k, aux_dtype, has_mem):
     """Stage 4a: one pullback per loss — P_s cotangent charges w_s (the aux
     loss rides with it), P_k cotangent yields the activation grads G_k."""
@@ -303,6 +312,7 @@ def _dual_pullbacks(vjp, g_s, g_k, aux_dtype, has_mem):
     return d_ws, g_x, g_mem
 
 
+@trace.scoped("client")
 def _client_pullback(model: SplitModel, wc, batch, acts, g_x, g_mem, has_mem):
     """Stage 4b (eq. 9): each client backprops its own G_k through its half."""
     g_x = g_x.reshape(acts["x"].shape)
@@ -381,60 +391,63 @@ def split_step_grads(model: SplitModel, params, batch, scala: ScalaConfig, *,
     weights = batch.get("weights")
     C = labels.shape[0]
 
-    if mask is not None:
-        mw = mask.astype(jnp.float32).reshape((C,) + (1,) * (labels.ndim - 1))
-        base_w = (jnp.ones(labels.shape, jnp.float32) if weights is None
-                  else jnp.broadcast_to(weights, labels.shape))
-        weights = base_w * mw
-
     # --- stage 1: label statistics (clients upload Y_k with A_k) ---
-    p_k, p_s = _priors(labels, weights, N, scala, axes)
+    with trace.scope("boundary"):
+        if mask is not None:
+            mw = mask.astype(jnp.float32).reshape(
+                (C,) + (1,) * (labels.ndim - 1))
+            base_w = (jnp.ones(labels.shape, jnp.float32) if weights is None
+                      else jnp.broadcast_to(weights, labels.shape))
+            weights = base_w * mw
+        p_k, p_s = _priors(labels, weights, N, scala, axes)
 
     # --- stage 2: parallel client forward (client-parallel == vmap) ---
-    acts = jax.vmap(lambda w, b: model.client_fwd(w, b))(params["client"],
-                                                         batch)
+    with trace.scope("client"):
+        acts = jax.vmap(lambda w, b: model.client_fwd(w, b))(
+            params["client"], batch)
     x = acts["x"]                                   # (C, B_k, ..., d)
 
     # --- stages 3-4: backend-specific dual losses over a shared vjp ---
     if backend == "logits":
         (logits, aux), vjp, has_mem = _server_vjp(model.server_fwd,
                                                   params["server"], acts)
-        labels_f = _flat(labels)
-        weights_f = _flat(weights) if weights is not None else None
+        with trace.scope("boundary"):
+            labels_f = _flat(labels)
+            weights_f = _flat(weights) if weights is not None else None
 
-        # both sides' priors, prepared once and shared between eq. (14)
-        # and eq. (15) — the per-client prior broadcast over each
-        # client's token dims
-        ps_use = p_s if scala.adjust_server else None
-        pk_tok = _prior_for_tokens(p_k, labels.shape)        # (C,1..,N)
-        pk_flat = _flat(jnp.broadcast_to(
-            pk_tok, labels.shape[:2] + (1,) * (labels.ndim - 2) + (N,)))
-        pk_use = pk_flat if scala.adjust_client else None
+            # both sides' priors, prepared once and shared between eq. (14)
+            # and eq. (15) — the per-client prior broadcast over each
+            # client's token dims
+            ps_use = p_s if scala.adjust_server else None
+            pk_tok = _prior_for_tokens(p_k, labels.shape)        # (C,1..,N)
+            pk_flat = _flat(jnp.broadcast_to(
+                pk_tok, labels.shape[:2] + (1,) * (labels.ndim - 2) + (N,)))
+            pk_use = pk_flat if scala.adjust_client else None
 
-        # the mirrored one-pass backward is bitwise only at ls == 0; the
-        # smoothed objective keeps the autodiff schedule
-        if boundary == "fused" and scala.label_smoothing == 0.0:
-            loss_s, loss_k, g_s, g_k = losses.dual_adjusted_xent(
-                logits, labels_f, weights=weights_f, prior_s=ps_use,
-                prior_k=pk_use, tau=scala.tau,
-                label_smoothing=scala.label_smoothing,
-                prior_eps=scala.prior_eps)
-        else:
-            def server_loss(lg):
-                return losses.softmax_xent(
-                    lg, labels_f, weights=weights_f, prior=ps_use,
-                    tau=scala.tau, label_smoothing=scala.label_smoothing,
+            # the mirrored one-pass backward is bitwise only at ls == 0; the
+            # smoothed objective keeps the autodiff schedule
+            if boundary == "fused" and scala.label_smoothing == 0.0:
+                loss_s, loss_k, g_s, g_k = losses.dual_adjusted_xent(
+                    logits, labels_f, weights=weights_f, prior_s=ps_use,
+                    prior_k=pk_use, tau=scala.tau,
+                    label_smoothing=scala.label_smoothing,
                     prior_eps=scala.prior_eps)
+            else:
+                def server_loss(lg):
+                    return losses.softmax_xent(
+                        lg, labels_f, weights=weights_f, prior=ps_use,
+                        tau=scala.tau, label_smoothing=scala.label_smoothing,
+                        prior_eps=scala.prior_eps)
 
-            loss_s, g_s = jax.value_and_grad(server_loss)(logits)
+                loss_s, g_s = jax.value_and_grad(server_loss)(logits)
 
-            def client_loss(lg):
-                return losses.softmax_xent(
-                    lg, labels_f, weights=weights_f, prior=pk_use,
-                    tau=scala.tau, label_smoothing=scala.label_smoothing,
-                    prior_eps=scala.prior_eps)
+                def client_loss(lg):
+                    return losses.softmax_xent(
+                        lg, labels_f, weights=weights_f, prior=pk_use,
+                        tau=scala.tau, label_smoothing=scala.label_smoothing,
+                        prior_eps=scala.prior_eps)
 
-            loss_k, g_k = jax.value_and_grad(client_loss)(logits)
+                loss_k, g_k = jax.value_and_grad(client_loss)(logits)
 
         d_ws, g_x, g_mem = _dual_pullbacks(vjp, g_s, g_k, aux.dtype, has_mem)
         metrics = {"loss_server": loss_s, "loss_client": loss_k, "aux": aux,
@@ -448,80 +461,83 @@ def split_step_grads(model: SplitModel, params, batch, scala: ScalaConfig, *,
             ce_chunk = default_ce_chunk(N)
         (feats, aux), vjp, has_mem = _server_vjp(model.server_trunk,
                                                  params["server"], acts)
-        d = feats.shape[-1]
-        feats_g = feats.reshape(C, -1, d)           # (C, bk*s_out, d)
-        labels_g = labels.reshape(C, -1)
-        weights_g = None if weights is None else weights.reshape(C, -1)
-        w_head = model.head_weight(params["server"])
+        with trace.scope("boundary"):
+            d = feats.shape[-1]
+            feats_g = feats.reshape(C, -1, d)           # (C, bk*s_out, d)
+            labels_g = labels.reshape(C, -1)
+            weights_g = None if weights is None else weights.reshape(C, -1)
+            w_head = model.head_weight(params["server"])
 
-        ps_rows = p_s[None] if scala.adjust_server else None
-        pk_rows = p_k if scala.adjust_client else None
-        pk_ids = jnp.arange(C) if scala.adjust_client else None
+            ps_rows = p_s[None] if scala.adjust_server else None
+            pk_rows = p_k if scala.adjust_client else None
+            pk_ids = jnp.arange(C) if scala.adjust_client else None
 
-        if backend == "lace" and boundary == "fused":
-            lace2 = lace2_grads_dp if model.dp_loss else lace2_grads
-            loss_s, loss_k, gf_s, gf_k, gW_s = lace2(
-                feats_g, w_head, labels_g, ps_rows, None, pk_rows, pk_ids,
-                weights_g, scala.tau, scala.prior_eps, ce_chunk)[:5]
-        elif backend == "lace":
-            lace = lace_loss_dp if model.dp_loss else lace_loss
+            if backend == "lace" and boundary == "fused":
+                lace2 = lace2_grads_dp if model.dp_loss else lace2_grads
+                loss_s, loss_k, gf_s, gf_k, gW_s = lace2(
+                    feats_g, w_head, labels_g, ps_rows, None, pk_rows, pk_ids,
+                    weights_g, scala.tau, scala.prior_eps, ce_chunk)[:5]
+            elif backend == "lace":
+                lace = lace_loss_dp if model.dp_loss else lace_loss
 
-            # eq. (14): concatenated prior P_s for the server update
-            def loss_s_fn(fg, wh):
-                return lace(fg, wh, labels_g, ps_rows, None, weights_g,
-                            scala.tau, scala.prior_eps, ce_chunk)
+                # eq. (14): concatenated prior P_s for the server update
+                def loss_s_fn(fg, wh):
+                    return lace(fg, wh, labels_g, ps_rows, None, weights_g,
+                                scala.tau, scala.prior_eps, ce_chunk)
 
-            loss_s, (gf_s, gW_s) = jax.value_and_grad(
-                loss_s_fn, argnums=(0, 1))(feats_g, w_head)
+                loss_s, (gf_s, gW_s) = jax.value_and_grad(
+                    loss_s_fn, argnums=(0, 1))(feats_g, w_head)
 
-            # eq. (15): per-client priors P_k for the gradients G_k
-            def loss_k_fn(fg):
-                return lace(fg, w_head, labels_g, pk_rows, pk_ids,
-                            weights_g, scala.tau, scala.prior_eps, ce_chunk)
+                # eq. (15): per-client priors P_k for the gradients G_k
+                def loss_k_fn(fg):
+                    return lace(fg, w_head, labels_g, pk_rows, pk_ids,
+                                weights_g, scala.tau, scala.prior_eps,
+                                ce_chunk)
 
-            loss_k, gf_k = jax.value_and_grad(loss_k_fn)(feats_g)
-        else:                                        # "lace_dp"
-            # differentiate LOCAL nll sums only (never through a psum: with
-            # vma checking off, the psum transpose would re-reduce an
-            # already-replicated cotangent and over-count by |axes|); the
-            # global normalization is applied to values/grads afterwards.
-            wsum_local = (jnp.sum(weights_g) if weights_g is not None
-                          else jnp.float32(labels_g.size))
-            w_global = jnp.maximum(jax.lax.psum(
-                jnp.asarray(wsum_local, jnp.float32), axes.all), 1e-8)
+                loss_k, gf_k = jax.value_and_grad(loss_k_fn)(feats_g)
+            else:                                        # "lace_dp"
+                # differentiate LOCAL nll sums only (never through a psum: with
+                # vma checking off, the psum transpose would re-reduce an
+                # already-replicated cotangent and over-count by |axes|); the
+                # global normalization is applied to values/grads afterwards.
+                wsum_local = (jnp.sum(weights_g) if weights_g is not None
+                              else jnp.float32(labels_g.size))
+                w_global = jnp.maximum(jax.lax.psum(
+                    jnp.asarray(wsum_local, jnp.float32), axes.all), 1e-8)
 
-            if boundary == "fused":
-                nll_s, nll_k, gf_s, gf_k, gW_s, _ = lace2_grads(
-                    feats_g, w_head, labels_g, ps_rows, None, pk_rows,
-                    pk_ids, weights_g, scala.tau, scala.prior_eps,
-                    ce_chunk, mean=False)
-            else:
-                def nll_s_fn(fg, wh):
-                    return lace_nll_sum(fg, wh, labels_g, ps_rows, None,
-                                        weights_g, scala.tau,
-                                        scala.prior_eps, ce_chunk)
+                if boundary == "fused":
+                    nll_s, nll_k, gf_s, gf_k, gW_s, _ = lace2_grads(
+                        feats_g, w_head, labels_g, ps_rows, None, pk_rows,
+                        pk_ids, weights_g, scala.tau, scala.prior_eps,
+                        ce_chunk, mean=False)
+                else:
+                    def nll_s_fn(fg, wh):
+                        return lace_nll_sum(fg, wh, labels_g, ps_rows, None,
+                                            weights_g, scala.tau,
+                                            scala.prior_eps, ce_chunk)
 
-                nll_s, (gf_s, gW_s) = jax.value_and_grad(
-                    nll_s_fn, argnums=(0, 1))(feats_g, w_head)
+                    nll_s, (gf_s, gW_s) = jax.value_and_grad(
+                        nll_s_fn, argnums=(0, 1))(feats_g, w_head)
 
-                def nll_k_fn(fg):
-                    return lace_nll_sum(fg, w_head, labels_g, pk_rows,
-                                        pk_ids, weights_g, scala.tau,
-                                        scala.prior_eps, ce_chunk)
+                    def nll_k_fn(fg):
+                        return lace_nll_sum(fg, w_head, labels_g, pk_rows,
+                                            pk_ids, weights_g, scala.tau,
+                                            scala.prior_eps, ce_chunk)
 
-                nll_k, gf_k = jax.value_and_grad(nll_k_fn)(feats_g)
+                    nll_k, gf_k = jax.value_and_grad(nll_k_fn)(feats_g)
 
-            loss_s = jax.lax.psum(nll_s, axes.all) / w_global
-            gf_s = gf_s / w_global
-            gW_s = gW_s / w_global
-            loss_k = jax.lax.psum(nll_k, axes.all) / w_global
-            gf_k = gf_k / w_global
+                loss_s = jax.lax.psum(nll_s, axes.all) / w_global
+                gf_s = gf_s / w_global
+                gW_s = gW_s / w_global
+                loss_k = jax.lax.psum(nll_k, axes.all) / w_global
+                gf_k = gf_k / w_global
 
-        gf_s_t = gf_s.reshape(feats.shape).astype(feats.dtype)
-        gf_k_t = gf_k.reshape(feats.shape).astype(feats.dtype)
+            gf_s_t = gf_s.reshape(feats.shape).astype(feats.dtype)
+            gf_k_t = gf_k.reshape(feats.shape).astype(feats.dtype)
         d_ws, g_x, g_mem = _dual_pullbacks(vjp, gf_s_t, gf_k_t, aux.dtype,
                                            has_mem)
-        d_ws = model.head_grad_merge(d_ws, gW_s)
+        with trace.scope("boundary"):
+            d_ws = model.head_grad_merge(d_ws, gW_s)
         metrics = {"loss_server": loss_s, "loss_client": loss_k, "aux": aux}
 
     # --- stage 4 reductions (manual-SPMD only) ---
@@ -532,17 +548,19 @@ def split_step_grads(model: SplitModel, params, batch, scala: ScalaConfig, *,
         # (the psum transpose passes the global cotangent through, so
         # grads wrt replicated weights are per-shard contributions);
         # optionally compressed to bf16 (halves the remaining wire traffic).
-        if rdt is not None:
-            d_ws = jax.tree.map(lambda g: g.astype(rdt), d_ws)
-        d_ws = jax.lax.psum(d_ws, axes.all)
+        with trace.scope("trunk"):
+            if rdt is not None:
+                d_ws = jax.tree.map(lambda g: g.astype(rdt), d_ws)
+            d_ws = jax.lax.psum(d_ws, axes.all)
 
     d_wc = _client_pullback(model, params["client"], batch, acts, g_x, g_mem,
                             has_mem)
     if axes is not None and axes.inner:
         # each client's batch is itself sharded over the inner axis
-        if rdt is not None:
-            d_wc = jax.tree.map(lambda g: g.astype(rdt), d_wc)
-        d_wc = jax.lax.psum(d_wc, axes.inner)
+        with trace.scope("client"):
+            if rdt is not None:
+                d_wc = jax.tree.map(lambda g: g.astype(rdt), d_wc)
+            d_wc = jax.lax.psum(d_wc, axes.inner)
     if axes is not None:
         metrics["aux"] = jax.lax.pmean(metrics["aux"], axes.all)
 
@@ -554,6 +572,7 @@ def split_step_grads(model: SplitModel, params, batch, scala: ScalaConfig, *,
 # ---------------------------------------------------------------------------
 
 
+@trace.scoped("update")
 def sgd_apply(params, grads, lr):
     """The paper's eq. (7)/(9) update, in param dtype (legacy-exact)."""
     return jax.tree.map(lambda w, g: w - lr * g.astype(w.dtype),
@@ -583,6 +602,7 @@ def init_train_state(params, optimizer: optimizers.Optimizer) -> TrainState:
         step=jnp.zeros((), jnp.int32))
 
 
+@trace.scoped("update")
 def _apply_updates(opt: optimizers.Optimizer, state: TrainState, grads,
                    lr) -> TrainState:
     new_s, st_s = opt.update(grads["server"], state.opt_state["server"],
@@ -1123,6 +1143,7 @@ def make_round_runner(model: SplitModel, scala: ScalaConfig, *,
                 out_specs=(s_specs, m_specs), check_vma=False)
             return fn(state, round_batches, mask, sizes)
 
+    @trace.scoped("fed")
     def round_fn(state: TrainState, round_batches, data_sizes=None,
                  fed_state=None):
         if fed_state is None:

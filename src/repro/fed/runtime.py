@@ -115,6 +115,7 @@ from repro.core.split import (normalize_client_weights, stack_client_params,
 from repro.fed import aggregators as _agg
 from repro.fed.delays import DelayModel
 from repro.optim import optimizers, schedules
+from repro.perf import trace
 
 #: snapshot storage layouts for :class:`AsyncFedState`.
 SNAPSHOT_MODES = ("dense", "delta")
@@ -798,6 +799,7 @@ def make_async_runner(model: engine.SplitModel, scala: ScalaConfig, *,
                                   optimizer=opt, schedule=sched,
                                   ce_chunk=ce_chunk, precision=precision)
 
+    @trace.scoped("fed")
     def async_fn(state: engine.TrainState, afed: AsyncFedState,
                  round_batches, data_sizes=None, cohort_opt=None):
         K = afed.version.shape[0]
@@ -1158,6 +1160,7 @@ def _make_async_runner_dp(model, scala, *, boundary, delays, cohort, opt,
     if emit_client_metrics:
         m_specs.update(arrival_mask=cspec, staleness=cspec)
 
+    @trace.scoped("fed")
     def async_fn(state: engine.TrainState, afed: AsyncFedState,
                  round_batches, data_sizes=None):
         K = afed.version.shape[0]
