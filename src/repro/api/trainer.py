@@ -2,7 +2,8 @@
 
 The trainer owns the *host* side of an experiment: synthesizing the
 dataset from :class:`repro.api.DataSpec`, assembling per-round batches
-(eq. 3 sizing via :mod:`repro.data.loader`), threading the
+(eq. 3 sizing via :mod:`repro.data.loader`; image rows stay on the
+device and a round uploads only the row ids it drew), threading the
 :class:`repro.api.build.ProgramState` through the built
 :class:`repro.api.build.RoundProgram`, and evaluation. Everything
 jit-compiled lives in the program; everything numpy lives here.
@@ -30,8 +31,10 @@ from __future__ import annotations
 import json
 import os
 import time
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -83,6 +86,16 @@ def build_image_data(spec: ExperimentSpec):
             (x[d.n_train:], y[d.n_train:]))
 
 
+@partial(jax.jit, static_argnums=3)
+def _gather_rows(pool_x, pool_y, rows, row_shape):
+    """A round's image batches from the device pool (``pool_x`` holds
+    one flat row per image): exactly what ``round_batches`` assembles on
+    the host for the same rows."""
+    return {"x": pool_x[rows].reshape(rows.shape + row_shape),
+            "labels": pool_y[rows],
+            "weights": (rows < pool_y.shape[0] - 1).astype(jnp.float32)}
+
+
 # ---------------------------------------------------------------------------
 # the trainer
 # ---------------------------------------------------------------------------
@@ -106,8 +119,13 @@ class Trainer:
         self.state = self.program.init()
         self.history: List[Dict[str, float]] = []
         self.round = 0
-        # bytes of host batches handed to the device, sizes included
+        # bytes handed to jnp.asarray: for image data the pool once, then
+        # each round's rows and sizes; for LM data each round's batches
         self.upload_bytes = 0
+        # bytes of the image pool on the device; rounds gathered from it
+        self.resident_bytes = 0
+        self.gathered_rounds = 0
+        self._pool = self._pool_of = None
         self._rpc = self.program.metadata.get("rounds_per_call", 1)
         self._cfg = spec.model_config()
         if spec.data.kind == "image_synthetic":
@@ -122,8 +140,11 @@ class Trainer:
 
     # ------------------------------------------------------------------
 
-    def _next_round_batches(self):
-        from repro.data.loader import (lm_round_batches, round_batches,
+    def _draw_round(self):
+        """One round's host draw, in the RNG order of the pre-API
+        drivers: (rows, sizes) for image data, rows being global ids into
+        the device pool; (batches, sizes) for LM data."""
+        from repro.data.loader import (lm_round_batches, round_rows,
                                        sample_clients)
 
         spec, sc = self.spec, self.spec.scala
@@ -136,14 +157,46 @@ class Trainer:
             budget = (round(sc.server_batch / sc.participation)
                       if spec.execution.mode in ("masked", "sparse")
                       else sc.server_batch)
-            rb = round_batches(self._data, selected, budget, sc.local_iters,
-                               self._rng)
-        else:
-            rb = lm_round_batches(self._data, selected, sc.server_batch,
-                                  sc.local_iters, self._rng)
-        self.upload_bytes += sum(v.nbytes for v in rb.values())
-        sizes = jnp.asarray(rb.pop("sizes"))
-        return {k: jnp.asarray(v) for k, v in rb.items()}, sizes
+            return round_rows(self._data, selected, budget, sc.local_iters,
+                              self._rng)
+        rb = lm_round_batches(self._data, selected, sc.server_batch,
+                              sc.local_iters, self._rng)
+        return rb, rb.pop("sizes")
+
+    def _upload(self, a: np.ndarray):
+        self.upload_bytes += a.nbytes
+        return jnp.asarray(a)
+
+    def _device_pool(self):
+        """The image clients' rows on the device (``pool_arrays``), built
+        on first use and again whenever ``_data`` is replaced."""
+        from repro.data.loader import pool_arrays
+
+        if self._pool_of is not self._data:
+            self._pool = self._pool_of = None    # free the old pool first
+            x, y = pool_arrays(self._data)
+            # flat rows: the TPU lays out (N + 1, H, W, C) with N minor,
+            # which a row gather would have to relayout whole every call
+            self._pool = (self._upload(x.reshape(len(x), -1)),
+                          self._upload(y), x.shape[1:])
+            self._pool_of = self._data
+            self.resident_bytes = sum(a.nbytes for a in self._pool[:2])
+        return self._pool
+
+    def _next_round_batches(self, rounds: Optional[int] = None):
+        """Device batches and sizes of one round, or of ``rounds`` rounds
+        stacked along a leading round axis. Image rounds upload only
+        their row ids and gather the batch from the device pool."""
+        draws = [self._draw_round() for _ in range(rounds or 1)]
+        stack = (lambda xs: xs[0]) if rounds is None else np.stack
+        sizes = self._upload(stack([s for _, s in draws]))
+        if self.spec.data.kind == "image_synthetic":
+            rows = self._upload(stack([r for r, _ in draws]))
+            self.gathered_rounds += len(draws)
+            pool_x, pool_y, row_shape = self._device_pool()
+            return _gather_rows(pool_x, pool_y, rows, row_shape), sizes
+        return ({k: self._upload(stack([b[k] for b, _ in draws]))
+                 for k in draws[0][0]}, sizes)
 
     # ------------------------------------------------------------------
 
@@ -172,10 +225,7 @@ class Trainer:
             self.round += 1
             return scalars
         with trace.span("trainer.batches"):
-            per_round = [self._next_round_batches() for _ in range(n)]
-            batches = {k: jnp.stack([b[k] for b, _ in per_round])
-                       for k in per_round[0][0]}
-            sizes = jnp.stack([s for _, s in per_round])
+            batches, sizes = self._next_round_batches(n)
         self.state, metrics = self.program.step(self.state, batches, sizes)
         # per-round scalars carry the leading (n,) round axis now; ONE
         # device-to-host pull per metric for the whole chunk

@@ -5,6 +5,9 @@ Implements the paper's round protocol on the host side:
 * eq. (3) minibatch sizing B_k ∝ |D_k| (padded to max B_k with
   zero-weight rows so client batches stack into a (C, B_k, ...) tensor),
 * T local-iteration minibatches per round: leaves (T, C, Bk, ...).
+
+The draw (:func:`round_rows`, global row ids) is separate from the copy,
+so the rows can be gathered from a pool that stays on the device.
 """
 from __future__ import annotations
 
@@ -41,33 +44,54 @@ def sample_clients(num_clients: int, num_selected: int,
     return rng.choice(num_clients, size=num_selected, replace=False)
 
 
+def pool_arrays(data: FederatedData):
+    """Every client's rows concatenated into one pool, plus a sentinel row
+    (index N, all zeros, label 0) that padding rows point at: x (N + 1,
+    ...) in the data's own dtype and labels (N + 1,) int32. Client k's
+    rows start at ``sum(sizes[:k])``."""
+    x0 = data.xs[0]
+    x = np.concatenate([*data.xs, np.zeros((1,) + x0.shape[1:], x0.dtype)],
+                       dtype=x0.dtype)
+    y = np.concatenate([*data.ys, np.zeros(1, np.int32)], dtype=np.int32)
+    return x, y
+
+
+def round_rows(data: FederatedData, selected: np.ndarray,
+               server_batch: int, local_iters: int,
+               rng: np.random.Generator):
+    """Draw one round's rows: global row ids (T, C, Bk) int32 into
+    :func:`pool_arrays` (padding rows hold the sentinel N), and the
+    selected clients' sizes (C,) float32 for eq. (10) aggregation.
+
+    eq. (3) sizing B_k ∝ |D_k|, padded to max B_k; one ``rng.choice``
+    per slot and local step, slot-major."""
+    all_sizes = data.sizes
+    sizes = all_sizes[selected]
+    bks = client_minibatch_sizes(sizes, server_batch)
+    starts = np.concatenate([[0], np.cumsum(all_sizes)[:-1]])
+    T, C = local_iters, len(selected)
+    rows = np.full((T, C, int(bks.max())), all_sizes.sum(), np.int32)
+    for ci, k in enumerate(selected):
+        n, bk = int(all_sizes[k]), int(bks[ci])
+        for t in range(T):
+            rows[t, ci, :bk] = starts[k] + rng.choice(n, size=bk,
+                                                      replace=n < bk)
+    return rows, sizes.astype(np.float32)
+
+
 def round_batches(data: FederatedData, selected: np.ndarray,
                   server_batch: int, local_iters: int,
                   rng: np.random.Generator,
                   x_key: str = "x") -> Dict[str, np.ndarray]:
     """Build one round's batches: {'x': (T,C,Bk,...), 'labels', 'weights'},
-    plus 'sizes' (C,) for eq. (10) aggregation."""
-    sizes = data.sizes[selected]
-    bks = client_minibatch_sizes(sizes, server_batch)
-    bk_max = int(bks.max())
-    T = local_iters
-    C = len(selected)
-
-    x_shape = data.xs[0].shape[1:]
-    xs = np.zeros((T, C, bk_max) + x_shape, data.xs[0].dtype)
-    ys = np.zeros((T, C, bk_max), np.int32)
-    ws = np.zeros((T, C, bk_max), np.float32)
-
-    for ci, k in enumerate(selected):
-        xk, yk = data.xs[k], data.ys[k]
-        bk = int(bks[ci])
-        for t in range(T):
-            idx = rng.choice(len(yk), size=bk, replace=len(yk) < bk)
-            xs[t, ci, :bk] = xk[idx]
-            ys[t, ci, :bk] = yk[idx]
-            ws[t, ci, :bk] = 1.0
-    return {x_key: xs, "labels": ys, "weights": ws,
-            "sizes": sizes.astype(np.float32)}
+    plus 'sizes' (C,) for eq. (10) aggregation. The host gather of
+    :func:`round_rows`' draw from :func:`pool_arrays`; padding rows are
+    zeros with label 0 and weight 0."""
+    rows, sizes = round_rows(data, selected, server_batch, local_iters, rng)
+    x, y = pool_arrays(data)
+    return {x_key: x[rows], "labels": y[rows],
+            "weights": (rows < len(y) - 1).astype(np.float32),
+            "sizes": sizes}
 
 
 def lm_round_batches(docs_by_client: List[np.ndarray], selected: np.ndarray,

@@ -13,8 +13,12 @@ The acceptance bars:
     targeted errors;
 (d) ``train.py --dump-config`` output fed back via ``--config``
     reproduces the identical run (same per-round metrics);
-(e) the legacy kwarg-style train.py helpers warn once per process.
+(e) the legacy kwarg-style train.py helpers warn once per process;
+(f) the Trainer's image rounds, gathered on the device from the clients'
+    resident rows, are bitwise the host's ``round_batches`` and leave the
+    host RNG where it left it.
 """
+import copy
 import json
 import warnings
 
@@ -464,3 +468,113 @@ def test_train_legacy_helpers_warn_once():
 
     with pytest.raises(AttributeError):
         train.not_a_helper
+
+
+# --------------------------------------------------------------------------
+# (f) image rounds gathered on the device from the resident pool
+# --------------------------------------------------------------------------
+
+
+def _ragged_data(seed, sizes=(7, 30, 3, 16)):
+    """Unequal client sizes, so eq. (3) pads the smaller clients' rows."""
+    from repro.data.loader import FederatedData
+
+    rng = np.random.default_rng(seed)
+    xs = [rng.normal(size=(n, 32, 32, 3)).astype(np.float32) for n in sizes]
+    ys = [rng.integers(0, 10, size=n) for n in sizes]
+    return FederatedData(xs=xs, ys=ys)
+
+
+def _pool_spec(mode, rpc=1):
+    part = "uniform:0.5" if mode in ("masked", "sparse") else None
+    return _image_spec(width=0.125, fed=api.FedSpec(participation=part),
+                       execution=api.ExecutionSpec(mode=mode, unroll=0,
+                                                   rounds_per_call=rpc))
+
+
+def _host_round(tr, rng):
+    """One round as the host assembled it before the pool: the trainer's
+    client draw, then ``round_batches``, on ``rng``."""
+    from repro.data.loader import round_batches, sample_clients
+
+    spec, sc = tr.spec, tr.spec.scala
+    selected = (np.arange(sc.num_clients) if spec.execution.in_program
+                else sample_clients(sc.num_clients, sc.clients_per_round,
+                                    rng))
+    budget = (round(sc.server_batch / sc.participation)
+              if spec.execution.mode in ("masked", "sparse")
+              else sc.server_batch)
+    return round_batches(tr._data, selected, budget, sc.local_iters, rng)
+
+
+def _assert_bitwise(got, want):
+    got = np.asarray(got)
+    assert (got.dtype, got.shape) == (want.dtype, want.shape)
+    assert got.tobytes() == want.tobytes()
+
+
+@pytest.mark.parametrize("mode,rpc", [("masked", 1), ("sparse", 1),
+                                      ("subset", 1), ("sparse", 2)])
+def test_device_gather_is_round_batches_bitwise(mode, rpc):
+    tr = api.Trainer(_pool_spec(mode, rpc))
+    tr._data = _ragged_data(1)
+    for _ in range(2):
+        rng = copy.deepcopy(tr._rng)
+        want = [_host_round(tr, rng) for _ in range(rpc)]
+        if rpc > 1:
+            want = {k: np.stack([w[k] for w in want]) for k in want[0]}
+        else:
+            (want,) = want
+        batches, sizes = tr._next_round_batches(rpc if rpc > 1 else None)
+        assert (want["weights"] == 0).any()       # padding rows present
+        assert set(batches) == {"x", "labels", "weights"}
+        for k, v in batches.items():
+            _assert_bitwise(v, want[k])
+        _assert_bitwise(sizes, want["sizes"])
+        assert tr._rng.bit_generator.state == rng.bit_generator.state
+
+
+def test_replaced_data_rebuilds_the_pool_and_counts_uploads():
+    from repro.data.loader import pool_arrays
+
+    tr = api.Trainer(_pool_spec("sparse"))
+    assert (tr.upload_bytes, tr.resident_bytes, tr.gathered_rounds) == (
+        0, 0, 0)
+    tr.step()
+    first_pool = sum(a.nbytes for a in pool_arrays(tr._data))
+    assert tr.resident_bytes == first_pool
+    per_round = tr.upload_bytes - first_pool
+    # rows (T, K, Bk) int32 and sizes (K,) float32
+    assert 0 < per_round < first_pool / 100
+
+    tr._data = _ragged_data(2)            # as bench/harness.Job does
+    rng = copy.deepcopy(tr._rng)
+    want = _host_round(tr, rng)
+    batches, sizes = tr._next_round_batches()
+    for k, v in batches.items():
+        _assert_bitwise(v, want[k])
+    new_pool = sum(a.nbytes for a in pool_arrays(tr._data))
+    assert tr.resident_bytes == new_pool
+    round_bytes = (want["labels"].size * 4) + want["sizes"].nbytes
+    assert tr.upload_bytes == (first_pool + per_round + new_pool
+                               + round_bytes)
+    tr.step()
+    tr.step()
+    assert tr.resident_bytes == new_pool     # the same data: no rebuild
+    assert tr.upload_bytes == first_pool + per_round + new_pool + 3 * round_bytes
+    assert tr.round == 3 and tr.gathered_rounds == 4
+
+
+def test_lm_rounds_gather_nothing():
+    spec = api.ExperimentSpec(
+        arch="qwen1.5-0.5b", reduced=True, rounds=2, seed=0,
+        scala=ScalaConfig(num_clients=2, participation=0.5, local_iters=1,
+                          server_batch=2),
+        fed=api.FedSpec(participation="uniform:0.5"),
+        execution=api.ExecutionSpec(mode="masked"),
+        data=api.DataSpec(kind="lm_synthetic", seq=8, docs_per_client=2))
+    tr = api.Trainer(spec)
+    tr.run()
+    assert tr.round == 2
+    assert (tr.gathered_rounds, tr.resident_bytes) == (0, 0)
+    assert tr._pool is None

@@ -1,7 +1,8 @@
 import numpy as np
 import pytest
 
-from repro.data.loader import FederatedData, lm_round_batches, round_batches, sample_clients
+from repro.data.loader import (FederatedData, lm_round_batches, pool_arrays,
+                               round_batches, round_rows, sample_clients)
 from repro.data.partition import dirichlet_skew, partition, quantity_skew
 from repro.data.synthetic import gaussian_images, token_stream
 
@@ -62,6 +63,36 @@ def test_round_batches_shapes_and_weights():
         real = rb["weights"][0, ci].sum()
         assert real >= 1
     assert rb["sizes"].shape == (4,)
+
+
+def test_round_rows_are_global_ids_with_a_sentinel():
+    rng = np.random.default_rng(0)
+    sizes = (7, 30, 3, 16)
+    data = FederatedData(
+        xs=[rng.normal(size=(n, 2, 2, 3)).astype(np.float32) for n in sizes],
+        ys=[rng.integers(0, 10, size=n) for n in sizes])
+    x, y = pool_arrays(data)
+    N = sum(sizes)
+    assert x.shape == (N + 1, 2, 2, 3) and x.dtype == np.float32
+    assert y.shape == (N + 1,) and y.dtype == np.int32
+    assert not x[N].any() and y[N] == 0
+
+    sel = np.array([3, 0, 1])
+    rows, got_sizes = round_rows(data, sel, server_batch=20, local_iters=2,
+                                 rng=np.random.default_rng(4))
+    assert rows.dtype == np.int32 and rows.shape[:2] == (2, 3)
+    np.testing.assert_array_equal(got_sizes, np.array(sizes)[sel])
+    starts = np.concatenate([[0], np.cumsum(sizes)[:-1]])
+    for ci, k in enumerate(sel):
+        real = rows[:, ci][rows[:, ci] < N]
+        assert ((real >= starts[k]) & (real < starts[k] + sizes[k])).all()
+    assert (rows == N).any()                   # client 0 is padded
+
+    rb = round_batches(data, sel, server_batch=20, local_iters=2,
+                       rng=np.random.default_rng(4))
+    np.testing.assert_array_equal(rb["x"], x[rows])
+    np.testing.assert_array_equal(rb["labels"], y[rows])
+    np.testing.assert_array_equal(rb["weights"], rows < N)
 
 
 def test_lm_round_batches_next_token():

@@ -558,6 +558,32 @@ def test_trainer_resume_bitwise_sync_masked(tmp_path):
     resumed.run(1)
     _tree_equal(straight.state, resumed.state, "full ProgramState")
     assert straight.history == resumed.history
+    # the resumed round was gathered from a pool the new trainer built
+    assert resumed.gathered_rounds == 1 and resumed.resident_bytes > 0
+    assert (resumed._rng.bit_generator.state
+            == straight._rng.bit_generator.state)
+
+
+def test_trainer_resume_bitwise_sparse_fused(tmp_path):
+    """Sparse rounds, two per call, gathered on the device: the resumed
+    run is bitwise the uninterrupted one."""
+    def mk():
+        return _tiny_image_spec(
+            fed=api.FedSpec(participation="uniform:0.5"),
+            execution=api.ExecutionSpec(mode="sparse", rounds_per_call=2))
+
+    straight = api.Trainer(mk())
+    straight.run(4)
+    d = str(tmp_path / "ckpt")
+    first = api.Trainer(mk())
+    first.run(2)
+    first.save(d)
+    resumed = api.Trainer(mk())
+    assert resumed.resume(d) == 2
+    resumed.run(2)
+    _tree_equal(straight.state, resumed.state, "full ProgramState")
+    assert straight.history == resumed.history
+    assert (straight.gathered_rounds, resumed.gathered_rounds) == (4, 2)
 
 
 def test_checkpoint_atomic_and_corrupt_fallback(tmp_path):
