@@ -143,12 +143,34 @@ def make_spec(cell: Cell, seed: int, **execution):
     _check_reference_terms(cell, spec, bool(execution))
     mc = spec.model_config()
     for k, v in cell.config["program_config"].items():
-        got = getattr(mc, k)
-        if (list(got) if isinstance(got, tuple) else got) != v:
-            raise ValueError(f"{cell.config['name']}: the program's "
-                             f"{k} is {got!r}, the configuration file "
-                             f"says {v!r}")
+        bad = config_difference(k, getattr(mc, k), v)
+        if bad:
+            raise ValueError(f"{cell.config['name']}: the program's {bad}")
     return spec
+
+
+def config_difference(name: str, got, want) -> Optional[str]:
+    """How the program's config value ``got`` differs from the file's
+    ``want`` (None where it does not). A dict in the file is a nested
+    sub-config (``moe``, ``mamba``, ``xlstm``): each of its keys is
+    compared with that field of the program's dataclass."""
+    if isinstance(want, dict):
+        if dataclasses.is_dataclass(got):
+            got = dataclasses.asdict(got)
+        if not isinstance(got, dict):
+            return (f"{name} is {got!r}, the configuration file says "
+                    f"{want!r}")
+        for k, v in want.items():
+            if k not in got:
+                return (f"{name} has no field {k!r}; the configuration "
+                        f"file says {v!r}")
+            bad = config_difference(f"{name}.{k}", got[k], v)
+            if bad:
+                return bad
+        return None
+    if (list(got) if isinstance(got, tuple) else got) != want:
+        return f"{name} is {got!r}, the configuration file says {want!r}"
+    return None
 
 
 def _check_reference_terms(cell: Cell, spec, overridden: bool) -> None:
@@ -620,6 +642,13 @@ def device_facts(chips: int):
     peak = peaks.lookup(devs[0].device_kind)
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs)}, peak
+
+
+def counters(trainer) -> Dict[str, int]:
+    """The trainer's public ``int`` attributes (``round``, ``upload_bytes``
+    and whatever counter the ``Trainer`` keeps besides)."""
+    return {k: v for k, v in vars(trainer).items()
+            if not k.startswith("_") and type(v) is int}
 
 
 def memory_peak_bytes() -> Optional[int]:

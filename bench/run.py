@@ -13,7 +13,13 @@ and the numbers compared are printed beside their limits.
 
 ``--trace 0`` prints the cell's end-to-end metrics; ``--trace 1`` runs
 a short window (at most ``TRACE_SECONDS``) under the profiler and
-prints the per-layer metrics. The
+prints the per-layer metrics. Each reader (``bench/metrics/<name>.py``,
+``read(ctx)``) gets in ``ctx``: ``trace`` (``trace_reduce``'s busy time
+and top ops), ``spans`` (``span_reduce``'s stage times, span idle and
+transfers, or None where they could not be read), ``counters`` (the
+change over the window of every public ``int`` attribute of the
+``Trainer``), ``round_metrics`` (the window's per-round scalars),
+``rounds``, ``peak``, ``flops_per_round`` and the ``cell``. The
 last line of standard output is the result; everything else goes to
 standard error. With no accelerator, or fewer chips than the cell
 asks for, it exits non-zero and prints no result.
@@ -47,6 +53,27 @@ def parse(argv):
     return ap.parse_args(argv)
 
 
+def read_spans(path: str):
+    """``span_reduce``'s summary of the trace, or None (logged) where it
+    cannot be read: the metrics that read the trace alone still print."""
+    import traceback
+
+    from bench import span_reduce
+    from bench.harness import log
+
+    t = time.perf_counter()
+    try:
+        spans = span_reduce.summarize(path)
+    except Exception:       # noqa: BLE001 -- any failure leaves spans out
+        log("span reduction failed; the metrics that read it are left "
+            f"out:\n{traceback.format_exc()}")
+        return None
+    log(f"span reduction {time.perf_counter() - t:.3f} s: stages "
+        f"{sorted(spans.stage_s)}, spans {sorted(spans.span_idle_s)}, "
+        f"{spans.h2d_events} host-to-device transfers")
+    return spans
+
+
 def run(args, cell=None, facts=None) -> dict:
     """One run. ``cell`` and ``facts`` (the device check) default to the
     workload's files and the accelerator; tests hand in their own."""
@@ -69,16 +96,25 @@ def run(args, cell=None, facts=None) -> dict:
 
     result = {"attempted": 0, "failed": 0}
     if args.trace:
+        before, seen = H.counters(job.trainer), len(job.trainer.history)
         tmp = tempfile.mkdtemp(prefix="bench_trace_")
         try:
             rounds = job.traced_window(min(args.seconds, H.TRACE_SECONDS),
                                        tmp)
-            summary = trace_reduce.summarize(trace_reduce.find_xplane(tmp))
+            path = trace_reduce.find_xplane(tmp)
+            summary = trace_reduce.summarize(path)
+            spans = read_spans(path)
         finally:
             shutil.rmtree(tmp, ignore_errors=True)
+        after = H.counters(job.trainer)
         ctx = {"trace": summary, "rounds": rounds, "peak": peak,
                "flops_per_round": cell.family.round_flops(
-                   cell.config, cell.traffic["expect"])}
+                   cell.config, cell.traffic["expect"]),
+               "spans": spans,
+               "counters": {k: v - before[k] for k, v in after.items()
+                            if k in before},
+               "round_metrics": job.trainer.history[seen:],
+               "cell": cell}
         metrics = {}
         for m in cell.metrics("per_layer"):
             v = H.metric_reader(m["name"])(ctx)
@@ -90,7 +126,7 @@ def run(args, cell=None, facts=None) -> dict:
         result["breakdown"] = {"device_ops": summary.top_ops,
                                "idle_gaps": summary.idle_gaps}
         H.log(f"traced {rounds} rounds: window {summary.window_s:.6f} s, "
-              f"busy {summary.busy_s:.6f} s")
+              f"busy {summary.busy_s:.6f} s, counters {ctx['counters']}")
     else:
         times, window_s, failed, wlog = job.window(args.seconds)
         result["attempted"], result["failed"] = len(times), failed

@@ -185,6 +185,28 @@ def summarize(path: str, top: int = 5) -> SpanSummary:
     return out
 
 
+def span_ms_per_round(ctx, seconds_of) -> Optional[float]:
+    """A metric reader's ms per round of the traced window, of the
+    seconds ``seconds_of`` takes from ``ctx["spans"]``. None where the
+    span reduction failed, no round ran, or ``seconds_of`` returns None
+    (the trace has nothing of it: not measured)."""
+    s = ctx.get("spans")
+    secs = None if s is None or not ctx["rounds"] else seconds_of(s)
+    return None if secs is None else 1000.0 * secs / ctx["rounds"]
+
+
+def stage_ms_per_round(ctx, stage: Optional[str]) -> Optional[float]:
+    """``span_ms_per_round`` of one stage's device self time (``None``:
+    the unscoped remainder). None where the trace carries no stage at all
+    (a program, or a cached executable, compiled without the scopes); 0.0
+    for a stage absent while others show (its ops fused into theirs)."""
+    def seconds(s):
+        if not s.stage_s:
+            return None
+        return s.unscoped_s if stage is None else s.stage_s.get(stage, 0.0)
+    return span_ms_per_round(ctx, seconds)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("trace", help="an .xplane.pb file")

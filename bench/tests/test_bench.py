@@ -25,13 +25,14 @@ ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import harness as H  # noqa: E402
-from bench import peaks, trace_reduce  # noqa: E402
+from bench import peaks, span_reduce, trace_reduce  # noqa: E402
 from bench import run as R  # noqa: E402
 from bench.models import alexnet_split, transformer_lm  # noqa: E402
 
 QWEN = "qwen1.5-0.5b.k4-masked"
 ALEX = "alexnet-cifar.k100-sparse"
 TRACE = ROOT / "bench" / "tests" / "data" / "tpu_v5e_trace.xplane.pb"
+SPANS = ROOT / "bench" / "tests" / "data" / "tpu_v5e_spans.xplane.pb"
 # program vs reference at float32 on the CPU: the same math in another
 # order, so every gap is round-off (measured 1e-8 to 3e-5), the three
 # rounds' losses and worst leaves too
@@ -156,6 +157,97 @@ def test_fault_in_timed_path_is_not_correct(make, fault, monkeypatch):
                for k, lim in cell.limits.items()) > 1.0
 
 
+# ---------------------------------------------------------------------------
+# a traced run: what it hands to the per-layer readers
+# ---------------------------------------------------------------------------
+
+OLD_METRICS = {"idle_share.train", "step_mfu.train",
+               "device_ms_per_round.train"}
+STAGE_METRICS = {f"{st}_ms_per_round.train" for st in (
+    "client", "trunk", "boundary", "update", "fed", "unscoped")}
+SPAN_METRICS = {"batches_idle_ms_per_round.train",
+                "sync_idle_ms_per_round.train", "h2d_ms_per_round.train"}
+HOST_METRICS = SPAN_METRICS | {"upload_mb_per_round.train"}
+
+
+def run_traced(monkeypatch, rounds=2):
+    """A ``--trace 1`` run of the tiny AlexNet cell whose traced window
+    drives ``rounds`` real rounds and leaves the recorded TPU trace as
+    its profile. Returns the result, the ``ctx`` the readers got, the
+    bytes the window uploaded, and the cell."""
+    seen = {}
+
+    def traced_window(self, seconds, directory):
+        tr = self.trainer
+        seen["upload_bytes"] = tr.upload_bytes
+        for _ in range(rounds):
+            tr.step()
+        seen["upload_bytes"] = tr.upload_bytes - seen["upload_bytes"]
+        shutil.copy(SPANS, Path(directory) / SPANS.name)
+        return rounds
+
+    real_reader = H.metric_reader
+    ctxs = []
+
+    def metric_reader(name, root=ROOT):
+        read = real_reader(name, root)
+        return lambda ctx: ctxs.append(ctx) or read(ctx)
+
+    monkeypatch.setattr(H.Job, "traced_window", traced_window)
+    monkeypatch.setattr(H, "metric_reader", metric_reader)
+    cell = tiny_alex()
+    args = R.parse(["--workload", cell.name, "--seed", str(2**31 + 5),
+                    "--seconds", "1", "--trace", "1"])
+    res = R.run(args, cell=cell, facts=cpu_facts)
+    return res, ctxs[0], seen, cell
+
+
+def test_traced_run_hands_spans_counters_and_rounds_to_the_readers(
+        monkeypatch):
+    res, ctx, seen, cell = run_traced(monkeypatch)
+    assert res["correct"] is True and res["attempted"] == 2
+    got = {k: v["value"] for k, v in res["metrics"].items()}
+    assert set(got) >= OLD_METRICS | STAGE_METRICS | HOST_METRICS
+    s = span_reduce.summarize(str(SPANS))
+    assert got["trunk_ms_per_round.train"] == 1000.0 * s.stage_s["trunk"] / 2
+    assert got["h2d_ms_per_round.train"] == 1000.0 * s.h2d_s / 2
+    assert got["upload_mb_per_round.train"] == (
+        seen["upload_bytes"] / 1e6 / 2) > 0
+    # every public int counter of the Trainer, as a change over the window
+    assert ctx["counters"]["round"] == ctx["counters"]["gathered_rounds"] == 2
+    assert ctx["counters"]["resident_bytes"] == 0     # the pool went up before
+    assert [sorted(h) for h in ctx["round_metrics"]] == 2 * [
+        sorted(ctx["round_metrics"][0])]
+    assert "loss_server" in ctx["round_metrics"][0]
+    assert ctx["cell"] is cell and ctx["rounds"] == 2
+
+
+def _raises(path):
+    raise RuntimeError("no framework_op_stats table")
+
+
+def _no_scopes(path):
+    return span_reduce.SpanSummary(
+        unscoped_s=0.01, span_idle_s={"trainer.batches": 0.002,
+                                      "trainer.sync": 0.0},
+        h2d_s=0.001, h2d_events=4)
+
+
+@pytest.mark.parametrize("summarize, missing", [
+    (_raises, STAGE_METRICS | SPAN_METRICS), (_no_scopes, STAGE_METRICS)],
+    ids=["reduction_fails", "no_scopes"])
+def test_traced_run_leaves_out_what_it_could_not_read(monkeypatch, summarize,
+                                                      missing):
+    # a failed span reduction keeps the trace's own metrics; a cached
+    # executable compiled without the scopes reads as not measured, not 0
+    monkeypatch.setattr(span_reduce, "summarize", summarize)
+    res, _, _, _ = run_traced(monkeypatch)
+    got = set(res["metrics"])
+    assert not got & missing
+    assert got >= (OLD_METRICS | STAGE_METRICS | HOST_METRICS) - missing
+    assert res["correct"] is True
+
+
 def test_limits_are_set_for_every_cell():
     bm = H.load_json(ROOT / "BENCHMARK.json")
     w = {"client": {"a": np.ones(2)}, "server": {"b": np.ones(2)}}
@@ -202,6 +294,30 @@ def test_diff_gaps_are_norms_of_the_weight_difference():
     got = H.compare(prog, ref)
     assert got["first_update_diff"] == (0.5, "['layers']['w'][0]")
     assert got["first_update_gap"][0] == 0.0
+
+
+def test_nested_program_config_is_compared_field_by_field():
+    cell = tiny_qwen()
+    cell.config["spec"]["argv"] = ["--arch", "qwen3-moe-30b-a3b",
+                                   "--precision", "f32", "--reduced"]
+    cell.config["program_config"] = {
+        "num_layers": 4, "moe": {"num_experts": 4, "top_k": 2,
+                                 "router_aux_weight": 0.01}}
+    assert H.make_spec(cell, 1).model_config().moe.top_k == 2
+    cell.config["program_config"]["moe"]["top_k"] = 8
+    with pytest.raises(ValueError, match="moe.top_k is 2, the configuration "
+                       "file says 8"):
+        H.make_spec(cell, 1)
+    cell.config["program_config"]["moe"] = {"experts_held": 4}
+    with pytest.raises(ValueError, match="moe has no field 'experts_held'"):
+        H.make_spec(cell, 1)
+    # a dense program has no moe block to compare
+    qwen = H.load_cell(QWEN)
+    assert H.make_spec(qwen, 1).model_config().moe is None
+    qwen.config = copy.deepcopy(qwen.config)
+    qwen.config["program_config"]["moe"] = {"top_k": 2}
+    with pytest.raises(ValueError, match="moe is None"):
+        H.make_spec(qwen, 1)
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,9 @@ They read ``data/tpu_v5e_spans.xplane.pb``, recorded on a TPU v5e by
 ``trainer.batches`` span that uploads a 2048 x 2048 float32 array, a
 jitted function with a scan under ``scala.trunk``, a sort under
 ``scala.fed`` and unscoped reductions, and a ``trainer.sync`` span. And
-they check that the older recorded trace still reduces as it did.
+they check that the older recorded trace still reduces as it did, and
+that the per-layer readers of the stages, spans and counters give the
+reductions per round, or nothing where the trace has no such names.
 """
 from __future__ import annotations
 
@@ -17,6 +19,7 @@ import pytest
 ROOT = Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
+from bench import harness as H  # noqa: E402
 from bench import span_reduce, trace_reduce  # noqa: E402
 
 DATA = ROOT / "bench" / "tests" / "data"
@@ -89,3 +92,61 @@ def test_older_recorded_trace_reduces_as_before():
     s = span_reduce.summarize(str(OLD))
     assert s.stage_s == {} and s.span_idle_s == {} and s.h2d_events == 0
     assert s.unscoped_s > 0
+
+
+# ---------------------------------------------------------------------------
+# the per-layer readers of the stage times, spans and counters
+# ---------------------------------------------------------------------------
+
+STAGES = ("client", "trunk", "boundary", "update", "fed")
+SPAN_READERS = [f"{st}_ms_per_round.train" for st in STAGES] + [
+    "unscoped_ms_per_round.train", "batches_idle_ms_per_round.train",
+    "sync_idle_ms_per_round.train", "h2d_ms_per_round.train"]
+READERS = SPAN_READERS + ["upload_mb_per_round.train"]
+
+
+def read_all(ctx):
+    return {n: H.metric_reader(n)(ctx) for n in READERS}
+
+
+def test_readers_are_the_reductions_per_round(reduced):
+    s, _ = reduced
+    ctx = {"spans": s, "rounds": 3, "counters": {"upload_bytes": 3 * UPLOAD}}
+    k = 1000.0 / 3
+    want = {f"{st}_ms_per_round.train": s.stage_s.get(st, 0.0) * k
+            for st in STAGES}
+    want.update({
+        "unscoped_ms_per_round.train": s.unscoped_s * k,
+        "batches_idle_ms_per_round.train":
+            s.span_idle_s["trainer.batches"] * k,
+        "sync_idle_ms_per_round.train": s.span_idle_s["trainer.sync"] * k,
+        "h2d_ms_per_round.train": s.h2d_s * k,
+        "upload_mb_per_round.train": UPLOAD / 1e6})
+    got = read_all(ctx)
+    assert got == pytest.approx(want, rel=1e-12)
+    # the recorded program has no client, boundary or update ops
+    assert [got[f"{st}_ms_per_round.train"] for st in
+            ("client", "boundary", "update")] == [0.0, 0.0, 0.0]
+    assert all(got[n] > 0 for n in ("trunk_ms_per_round.train",
+                                    "fed_ms_per_round.train"))
+
+
+def test_readers_say_not_measured_where_the_names_are_missing():
+    # the older trace: no scopes, no spans, no transfers; no counter
+    old = {"spans": span_reduce.summarize(str(OLD)), "rounds": 3,
+           "counters": {}}
+    assert read_all(old) == dict.fromkeys(READERS)
+    # the span reduction failed
+    gone = {"spans": None, "rounds": 3, "counters": {"upload_bytes": 30}}
+    assert read_all(gone) == dict(dict.fromkeys(SPAN_READERS),
+                                  **{"upload_mb_per_round.train": 1e-5})
+
+
+def test_readers_are_in_the_benchmark_for_every_training_cell():
+    bm = H.load_json(ROOT / "BENCHMARK.json")
+    entries = {m["name"]: m for m in bm["per_layer"]}
+    for n in READERS:
+        m = entries[n]
+        assert (m["moves"], m["better"], "workloads" in m) == (
+            "round_s", "lower", False)
+        assert m["unit"] == ("MB" if n.startswith("upload") else "ms")
